@@ -55,6 +55,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", type=str, default=None, help="run a single benchmark module by name")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.only:
         wanted = [m for m in mods if m.__name__.split(".")[-1] == args.only]
         if not wanted:

@@ -90,7 +90,7 @@ class TokenStream:
 @dataclass
 class FitResult:
     losses: List[float]  # per-round mean loss
-    state: Any  # final TrainState
+    state: Any  # final TrainState; the next fit() donates its buffers
     rounds: int
     steps: int  # local steps taken (rounds × τ)
     wall_s: float
@@ -105,6 +105,21 @@ class FitResult:
     @property
     def final_loss(self) -> float:
         return self.losses[-1] if self.losses else float("nan")
+
+
+def _unshared(state):
+    """The state with every leaf that repeats an earlier one copied: a
+    donated argument may not hold one buffer twice (strategies start their
+    in-flight slot as ``x`` itself)."""
+    seen = set()
+
+    def fresh(leaf):
+        if id(leaf) in seen:
+            return jnp.copy(leaf)
+        seen.add(id(leaf))
+        return leaf
+
+    return jax.tree.map(fresh, state)
 
 
 @dataclass
@@ -196,6 +211,9 @@ class Experiment:
             )
 
         self.state = make_train_state(self.params, self.workers, self.opt_obj, self.strategy_obj, self.axes)
+        # a round's input state is dead once its output exists: donating it
+        # lets the output reuse its buffers, so one copy of the state is on
+        # the device at a time
         self.step_fn = jax.jit(
             make_round_step(
                 self.loss_fn,
@@ -205,7 +223,8 @@ class Experiment:
                 self.axes,
                 grad_clip=self.grad_clip,
                 microbatch=self.microbatch,
-            )
+            ),
+            donate_argnums=(0,),
         )
         self._built = True
         return self
@@ -235,7 +254,10 @@ class Experiment:
         """Run the round loop. ``steps`` (local steps) is an alternative to
         ``rounds``: rounds = steps // τ. ``log(round_idx, mean_loss)`` is
         called once per round when given. Fitting continues from the current
-        state, so repeated calls accumulate training.
+        state, so repeated calls accumulate training. Each round donates its
+        input state, so a ``FitResult.state`` or ``exp.state`` taken before
+        a fit is dead after it; ``consensus()``, ``consensus_plane()`` and
+        ``anchor_plane()`` hand out buffers of their own.
 
         ``adaptive_tau`` hands the round loop to a
         :class:`repro.control.TauController` (DESIGN.md §6): each round runs
@@ -263,12 +285,12 @@ class Experiment:
         state = self.state
         for r in range(rounds):
             rb = round_batch(self.next_batch, tau)
-            state, ms = self.step_fn(state, rb)
+            state, ms = self.step_fn(_unshared(state), rb)
+            self.state = state  # the previous state was donated
             loss = float(np.asarray(ms["loss"]).mean())
             losses.append(loss)
             if log is not None:
                 log(r, loss)
-        self.state = state
         return FitResult(
             losses=losses, state=state, rounds=rounds, steps=rounds * tau, wall_s=time.time() - t0
         )
@@ -349,7 +371,8 @@ class Experiment:
             tau = ctrl.tau if ctrl is not None else self.strategy_obj.tau
             step = self.tau_programs.program_for(tau) if ctrl is not None else self.step_fn
             rb = round_batch(self.next_batch, tau)
-            state, ms = step(state, rb)
+            state, ms = step(_unshared(state), rb)
+            self.state = state
             losses.append(float(np.asarray(ms["loss"]).mean()))
             if ctrl is not None:
                 ctrl.update(
@@ -397,10 +420,10 @@ class Experiment:
         return Packed(bufs, x.layout)
 
     def anchor_plane(self):
-        """The live anchor plane z (the strategy's slow consensus weights),
-        shared by reference — zero-copy, so serving it reflects exactly the
-        buffers the trainer averages into. Anchor-based packed strategies
-        only."""
+        """A copy of the anchor plane z (the strategy's slow consensus
+        weights). A copy because the next ``fit()`` donates the round state,
+        z included: an engine serving z itself would lose its buffers.
+        Anchor-based packed strategies only."""
         from repro.parallel.packing import Packed
 
         if self.state is None:
@@ -408,7 +431,7 @@ class Experiment:
         z = getattr(self.state.vars, "z", None) if self.state.vars is not None else None
         if not isinstance(z, Packed):
             raise ValueError("anchor_plane() requires a packed anchor strategy (state.vars.z is the plane)")
-        return z
+        return Packed(tuple(jnp.copy(b) for b in z.buffers), z.layout)
 
     def evaluate(self, eval_batches: int = 8) -> dict:
         """Evaluate the consensus model: classification → held-out accuracy;

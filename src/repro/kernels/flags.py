@@ -1,9 +1,9 @@
 """Kernel dispatch flags.
 
-Pallas kernels target TPU; in this container they execute only in interpret
+Pallas kernels target TPU; on other backends they execute only in interpret
 mode. Model code consults :func:`use_pallas` so the same model definition
 runs (a) pure-jnp on CPU / in the dry-run lowering, (b) through the Pallas
-kernels on a real TPU or in interpret-mode kernel tests.
+kernels on a TPU or in interpret-mode kernel tests.
 """
 from __future__ import annotations
 

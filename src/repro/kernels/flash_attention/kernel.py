@@ -66,9 +66,10 @@ def _fa_kernel(
     p = jnp.exp(s - safe_m[:, None])
     corr = jnp.exp(m_prev - safe_m)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
+    # the TPU's matmul accumulates bf16 operands in f32 only when asked to
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-        p.astype(v_ref.dtype), v_ref[0]
-    ).astype(jnp.float32)
+        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
+    )
     m_ref[...] = m_new
 
     @pl.when(ki == pl.num_programs(2) - 1)

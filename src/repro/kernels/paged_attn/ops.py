@@ -32,12 +32,20 @@ def _pad_to(x, axis: int, mult: int):
 
 
 def _kernel_ok(pool) -> bool:
-    return flags.use_pallas() and pool.shape[1] % 8 == 0
+    """Whether a GQA decode step runs the Pallas kernels. Their page block
+    is a (page_size, head_dim) tile, so a page size the TPU cannot tile is
+    an error on that path, never a silent fallback to the reference."""
+    if not flags.use_pallas():
+        return False
+    page = ref.page_size_of(pool)
+    if page % 8:
+        raise ValueError(f"the Pallas paged-attention kernels need page_size % 8 == 0, got {page}")
+    return True
 
 
 def paged_append(pool, new, page_tables, lengths):
-    """(P, page, ...) pool ← (S, T, ...) new tokens. T == 1 on the GQA pool
-    shape routes to the Pallas scatter kernel."""
+    """(P, KV, page, D) / (P, page, r) pool ← (S, T, ...) new tokens. T == 1
+    on the GQA pool shape routes to the Pallas scatter kernel."""
     if new.ndim == 4 and new.shape[1] == 1 and _kernel_ok(pool):
         d = pool.shape[-1]
         dp = d + ((-d) % 128)
@@ -57,7 +65,7 @@ def paged_attend_gqa(q, pool_k, pool_v, page_tables, lengths, *, window: Optiona
     Pallas online-softmax kernel with page-table-driven index maps."""
     if q.shape[1] == 1 and _kernel_ok(pool_k):
         s_, _, h, d = q.shape
-        kv = pool_k.shape[2]
+        kv = pool_k.shape[1]
         g = h // kv
         qk = q.reshape(s_, kv, g, d)
         gp = g + ((-g) % 8)

@@ -1,8 +1,10 @@
 """Paged-KV attention — jnp reference bodies (DESIGN.md §10).
 
-Layout: a *pool* holds fixed-size pages shared by every request slot —
-``pool`` is ``(num_pages, page_size, ...)`` (GQA: trailing ``(kv_heads,
-head_dim)``; MLA: trailing ``(rank,)``). A per-slot *page table*
+Layout: a *pool* holds fixed-size pages shared by every request slot. A
+GQA pool is kv-head-major, ``(num_pages, kv_heads, page_size, head_dim)``,
+so one page of one kv head is a ``(page_size, head_dim)`` tile (the block
+the TPU decode kernel reads); an MLA pool is ``(num_pages, page_size,
+rank)``. The GQA pool is the one with four dims. A per-slot *page table*
 ``(slots, max_pages)`` maps logical page j of a slot to a physical page, and
 ``lengths (slots,)`` counts the tokens already resident, which is also the
 absolute position of the first token appended this call. Physical page 0 is
@@ -32,6 +34,17 @@ def paged_gather(pool: jnp.ndarray, page_tables: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *pool.shape[2:])
 
 
+def paged_gather_kv(pool: jnp.ndarray, page_tables: jnp.ndarray) -> jnp.ndarray:
+    """(P, KV, page, D) × (S, maxp) → (S, maxp·page, KV, D): a slot's GQA
+    cache in position order, in the dense cache's layout."""
+    return paged_gather(jnp.swapaxes(pool, 1, 2), page_tables)
+
+
+def page_size_of(pool: jnp.ndarray) -> int:
+    """Tokens per page of a GQA (4-D, kv-head-major) or MLA (3-D) pool."""
+    return pool.shape[2] if pool.ndim == 4 else pool.shape[1]
+
+
 def append_targets(
     page_tables: jnp.ndarray, lengths: jnp.ndarray, t: int, page_size: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -48,15 +61,17 @@ def append_targets(
 
 
 def paged_append(
-    pool: jnp.ndarray,  # (P, page, ...)
-    new: jnp.ndarray,  # (S, T, ...)
+    pool: jnp.ndarray,  # (P, KV, page, D) or (P, page, r)
+    new: jnp.ndarray,  # (S, T, KV, D) or (S, T, r)
     page_tables: jnp.ndarray,  # (S, maxp) int32
     lengths: jnp.ndarray,  # (S,) int32 — tokens resident before this append
 ) -> jnp.ndarray:
     """Scatter T new tokens per slot into their pages; O(tokens) writes, no
     cache growth or copy (the dense path's `_grow_all` pad-chain is exactly
     what this replaces)."""
-    page_ids, offsets = append_targets(page_tables, lengths, new.shape[1], pool.shape[1])
+    page_ids, offsets = append_targets(page_tables, lengths, new.shape[1], page_size_of(pool))
+    if pool.ndim == 4:  # the (S, T) index pair lands in front of (KV, D)
+        return pool.at[page_ids, :, offsets].set(new.astype(pool.dtype))
     return pool.at[page_ids, offsets].set(new.astype(pool.dtype))
 
 
@@ -72,7 +87,7 @@ def _causal_valid(lengths, t: int, l: int, window: Optional[int]):
 
 def paged_attend_gqa(
     q: jnp.ndarray,  # (S, T, H, D), pre-scaled
-    pool_k: jnp.ndarray,  # (P, page, KV, D)
+    pool_k: jnp.ndarray,  # (P, KV, page, D)
     pool_v: jnp.ndarray,
     page_tables: jnp.ndarray,  # (S, maxp)
     lengths: jnp.ndarray,  # (S,) — position of q[:, 0]
@@ -83,8 +98,8 @@ def paged_attend_gqa(
     `_decode_attend` op-for-op; T > 1 adds in-chunk causality for chunked
     prefill."""
     b, t, h, d = q.shape
-    k = paged_gather(pool_k, page_tables)  # (S, L, KV, D)
-    v = paged_gather(pool_v, page_tables)
+    k = paged_gather_kv(pool_k, page_tables)  # (S, L, KV, D)
+    v = paged_gather_kv(pool_v, page_tables)
     kvh = k.shape[2]
     g = h // kvh
     qg = q.reshape(b, t, kvh, g, d)

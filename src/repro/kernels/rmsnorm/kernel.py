@@ -24,7 +24,11 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm_2d(x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret: bool = False):
-    """x: (rows, d) — callers flatten leading dims. scale: (d,)."""
+    """x: (rows, d) — callers flatten leading dims. scale: (d,).
+
+    The scale rides as a (1, d) block: under ``vmap`` (worker-stacked
+    training) a batched 1-D (d,) block would leave a squeezed batch row as
+    its second-minor dim, which the TPU cannot tile."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
@@ -33,9 +37,9 @@ def rmsnorm_2d(x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret:
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
-    )(x, scale)
+    )(x, scale.reshape(1, d))

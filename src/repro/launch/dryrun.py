@@ -1,5 +1,8 @@
 import os
 
+# The dry-run meshes are host (CPU) placeholder devices: pin the CPU platform
+# so the tool never reaches for an attached accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # Append rather than overwrite: the 8-device test subprocesses (and any
 # caller that already pinned a host-device count) keep their value, a
 # pre-existing unrelated XLA_FLAGS (e.g. --xla_dump_to) is preserved, and

@@ -1,7 +1,8 @@
 import os
 
-# append (same contract as launch/dryrun.py): keep a caller-pinned device
-# count or unrelated XLA flags, default to the 512 placeholder devices
+# same contract as launch/dryrun.py: CPU placeholder devices only; keep a
+# caller-pinned device count or unrelated XLA flags, default to 512 devices
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"

@@ -13,6 +13,7 @@ import numpy as np
 from repro.config import get_arch, list_archs
 from repro.models import transformer as T
 from repro.serving import BatchedEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = arch.model if args.full else arch.model.reduced()

@@ -431,9 +431,9 @@ def cache_tree_shardings(caches, mesh: Mesh, rules: dict):
         if name in ("ckv", "krope"):  # (n?, B, L, r)
             lead = (None,) * (nd - 3)
             return P(*lead, b_ax, s_ax, None)
-        if name in ("pool_k", "pool_v"):  # (n?, P, page, kvh, hd) — shared pool:
+        if name in ("pool_k", "pool_v"):  # (n?, P, kvh, page, hd) — shared pool:
             lead = (None,) * (nd - 4)  # pages data-sharded, heads tensor-sharded
-            return P(*lead, b_ax, None, t_ax, None)
+            return P(*lead, b_ax, t_ax, None, None)
         if name in ("pool_ckv", "pool_krope"):  # (n?, P, page, r)
             lead = (None,) * (nd - 3)
             return P(*lead, b_ax, None, None)

@@ -21,6 +21,7 @@ from repro.api import Experiment, TokenStream
 from repro.config import AlgoConfig, OptimizerConfig, list_archs
 from repro.core import STRATEGIES
 from repro.optim import schedules
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true", help="use the full (not reduced) model config")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     exp = Experiment(
         arch=args.arch,

@@ -25,11 +25,10 @@ table derived from :class:`~repro.parallel.packing.Layout` bucket sizes,
 lead-agnostic so one plan serves the worker-stacked ``(m, n)`` opt
 buckets, the flat ``(n,)`` anchor, and f32 ``with_dtype`` shadows.
 
-Memory-kind placement is advisory: on backends that expose a
-``pinned_host`` memory space (TPU) every chunk hand-off is annotated
-with ``jax.device_put(..., TransferToMemoryKind(...))`` (legal inside
-jit on jax 0.4.x); on single-memory backends (CPU, where the only kind
-is ``unpinned_host``) the stream is structural-only and the annotations
+Memory-space placement: on backends that expose a ``pinned_host``
+memory (TPU, and the CPU backend) every chunk hand-off is annotated with
+``jax.device_put(..., jax.memory.Space.Host / .Device)`` inside jit; on a
+backend without one the stream is structural-only and the annotations
 are skipped.  The program shape — and therefore the parity and staging
 guarantees — is identical either way.  See DESIGN.md §9.
 """
@@ -44,14 +43,6 @@ import jax.numpy as jnp
 
 from repro.parallel.packing import LANE, Layout, Packed, _round_up
 
-try:  # jax 0.4.x keeps this in a private module; jax >= 0.5 re-exports it.
-    from jax.sharding import TransferToMemoryKind  # type: ignore
-except ImportError:  # pragma: no cover - version dependent
-    try:
-        from jax._src.sharding_impls import TransferToMemoryKind  # type: ignore
-    except ImportError:  # pragma: no cover
-        TransferToMemoryKind = None
-
 HOST_KIND = "pinned_host"
 DEFAULT_CHUNK_MB = 64.0
 
@@ -59,25 +50,18 @@ DEFAULT_CHUNK_MB = 64.0
 @functools.lru_cache(maxsize=None)
 def host_memory_kind() -> Optional[str]:
     """``"pinned_host"`` when the default backend has a distinct host
-    memory space, else ``None`` (single-memory backends: the stream is
-    structural and placement annotations are skipped)."""
-    if TransferToMemoryKind is None:
-        return None
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:  # pragma: no cover - backend without memories API
-        return None
+    memory space, else ``None`` (the stream is structural and placement
+    annotations are skipped)."""
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
     return HOST_KIND if HOST_KIND in kinds else None
 
 
 def _to_host(x):
-    kind = host_memory_kind()
-    return jax.device_put(x, TransferToMemoryKind(kind)) if kind else x
+    return jax.device_put(x, jax.memory.Space.Host) if host_memory_kind() else x
 
 
 def _to_device(x):
-    kind = host_memory_kind()
-    return jax.device_put(x, TransferToMemoryKind("device")) if kind else x
+    return jax.device_put(x, jax.memory.Space.Device) if host_memory_kind() else x
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +223,15 @@ def tree_restore(tree):
     ``Packed`` plane; other leaves pass through."""
     return jax.tree_util.tree_map(
         lambda x: restore_plane(x) if isinstance(x, HostPlane) else x,
+        tree,
+        is_leaf=_is_host,
+    )
+
+
+def tree_pin_host(tree):
+    """Annotate every :class:`HostPlane` chunk in ``tree`` as host memory."""
+    return jax.tree_util.tree_map(
+        lambda x: HostPlane(tuple(_to_host(c) for c in x.chunks), x.layout, x.plan) if isinstance(x, HostPlane) else x,
         tree,
         is_leaf=_is_host,
     )

@@ -338,6 +338,12 @@ def _read_windows_bwd(indices, layout, lead_shape, _res, cts):
         jnp.zeros(lead_shape + (n,), jnp.dtype(d))
         for n, d in zip(layout.bucket_sizes, layout.bucket_dtypes)
     ]
+    # materialize the leaf cotangents before placing them: fused into the
+    # plane writes, the embedding gather's transpose (a scatter-add) becomes
+    # a scatter into the whole plane, which the TPU compiler takes minutes
+    # over (a 1-layer h2o-danube round compiled for v5e: 371 s, 12 s with
+    # the barrier)
+    cts = jax.lax.optimization_barrier(tuple(cts))
     for i, ct in zip(indices, cts):
         slot = layout.slots[i]
         flat = jnp.reshape(ct, lead_shape + (slot.size,)).astype(bufs[slot.bucket].dtype)

@@ -25,12 +25,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: explicit/auto axis types on Mesh
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: Mesh has no axis_types — positional construction
-    AxisType = None
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config.base import ParallelPlan
 
@@ -93,12 +88,8 @@ _CTX = _Ctx()
 
 
 def make_auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported (jax ≥ 0.5);
-    plain construction on jax 0.4.x, where all mesh axes are implicitly
-    auto-sharded."""
-    if AxisType is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis auto-sharded."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes))
 
 
 def logical_mesh(production_mesh: Mesh, plan: ParallelPlan) -> Mesh:
@@ -112,9 +103,7 @@ def logical_mesh(production_mesh: Mesh, plan: ParallelPlan) -> Mesh:
     n = devices.size
     assert plan.num_devices == n, (plan, n)
     arr = devices.reshape(plan.workers, plan.fsdp, plan.tensor)
-    if AxisType is not None:
-        return Mesh(arr, ("worker", "fsdp", "tensor"), axis_types=(AxisType.Auto,) * 3)
-    return Mesh(arr, ("worker", "fsdp", "tensor"))
+    return Mesh(arr, ("worker", "fsdp", "tensor"), axis_types=(AxisType.Auto,) * 3)
 
 
 def spec_for(axes: Sequence[Optional[str]], rules: Optional[dict] = None) -> P:

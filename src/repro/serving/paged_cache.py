@@ -4,7 +4,7 @@ One pool per attention segment, with a leading layer axis so the existing
 ``lax.scan`` cache plumbing in ``apply_model`` slices a per-layer pool
 exactly like a per-layer dense cache:
 
-* GQA:  ``pool_k`` / ``pool_v``       — (n, num_pages, page_size, kv_heads, head_dim)
+* GQA:  ``pool_k`` / ``pool_v``       — (n, num_pages, kv_heads, page_size, head_dim)
 * MLA:  ``pool_ckv`` / ``pool_krope`` — (n, num_pages, page_size, rank)
 
 The page table and lengths are *not* part of the cache pytree: they are
@@ -75,8 +75,8 @@ def init_paged_pools(
             )
         else:
             pools[f"seg{si}"] = dict(
-                pool_k=jnp.zeros((n, num_pages, page_size, a.num_kv_heads, a.head_dim), dtype),
-                pool_v=jnp.zeros((n, num_pages, page_size, a.num_kv_heads, a.head_dim), dtype),
+                pool_k=jnp.zeros((n, num_pages, a.num_kv_heads, page_size, a.head_dim), dtype),
+                pool_v=jnp.zeros((n, num_pages, a.num_kv_heads, page_size, a.head_dim), dtype),
             )
     return pools
 

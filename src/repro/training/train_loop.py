@@ -200,6 +200,11 @@ def make_round_step(
                 # adoption: a resident state entering the offloaded engine
                 plan = off.OffloadPlan.for_layout(x0.layout, offload_chunk_mb)
                 opt0 = off.tree_offload(opt0, plan)
+            else:
+                # pin the carry's memory space to the host, as the streamed
+                # update leaves it (a no-op for host-resident arrays; it
+                # types abstract inputs, which carry no memory space)
+                opt0 = off.tree_pin_host(opt0)
             # prefetch (H2D) of the anchor-shaped state: vars ride the scan
             # carry, so they restore up front; the inflight slot restores up
             # front only for mid-round consumers (DaSGD) — otherwise right

@@ -510,3 +510,43 @@ def test_membership_boundary_lowers_and_runs_on_8_devices():
     proc = _run_subprocess(MEMBERSHIP_SCRIPT, "membership boundary")
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MEMBERSHIP MESH OK" in proc.stdout
+
+
+OFFLOAD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax
+from repro.config import AlgoConfig, get_arch, InputShape, ParallelPlan
+from repro.core import make_strategy
+from repro.launch import specs
+from repro.launch.mesh import make_smoke_mesh
+from repro.models import transformer as T
+from repro.optim import schedules, sgd
+from repro.parallel import mesh_context
+from repro.training import make_round_step
+
+mesh = make_smoke_mesh()
+cfg = get_arch("h2o-danube-1.8b").model.reduced()
+plan = ParallelPlan(workers=2, fsdp=2, tensor=2)
+shape = InputShape("small_train", seq_len=32, global_batch=8, mode="train")
+rules = specs.rules_for(shape)
+strat = make_strategy(AlgoConfig(name="overlap_local_sgd", tau=2, alpha=0.6, anchor_beta=0.7, offload=True))
+opt = sgd(momentum=0.9, nesterov=True)
+with mesh_context(mesh, rules):
+    state_sds, state_sh, axes = specs.train_state_specs(cfg, plan, strat, opt, mesh, rules)
+    batch_sds = specs.train_batch_specs(cfg, shape, plan, tau=2)
+    batch_sh = specs.batch_shardings(batch_sds, mesh, rules)
+    step = make_round_step(lambda p, b: T.lm_loss(cfg, p, b), opt, strat, schedules.constant(0.1), axes)
+    text = jax.jit(step, in_shardings=(state_sh, batch_sh)).lower(state_sds, batch_sds).as_text()
+    assert 'memory_kind = "pinned_host"' in text
+print("OFFLOAD LOWER OK")
+"""
+
+
+def test_offloaded_round_lowers_on_8_devices():
+    """The dry-run's abstract (shape-only) state carries no memory space:
+    the offloaded round program must still lower, with its opt-state
+    chunks placed in host memory."""
+    proc = _run_subprocess(OFFLOAD_SCRIPT, "offloaded lowering")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "OFFLOAD LOWER OK" in proc.stdout

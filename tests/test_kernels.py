@@ -33,6 +33,24 @@ def test_rmsnorm_sweep(rng, rows, d, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype))
 
 
+def test_rmsnorm_grads_match_reference(rng):
+    """The kernel op carries its own VJP (a pallas_call has no reverse-mode
+    rule), so training can differentiate through it — vmapped over workers
+    as the round step does."""
+    x = jnp.asarray(rng.normal(size=(2, 16, 128)), jnp.float32)
+    s = jnp.asarray(rng.normal(size=(2, 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(16, 128)), jnp.float32)
+
+    def loss(fn):
+        return lambda xx, ss: jnp.sum(jax.vmap(fn)(xx, ss) * w)
+
+    with flags.force_pallas():
+        got = jax.grad(loss(rms_ops.rmsnorm), argnums=(0, 1))(x, s)
+    want = jax.grad(loss(rms_ref.rmsnorm), argnums=(0, 1))(x, s)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize(
     "b,sq,sk,h,hkv,d,causal,window",
     [

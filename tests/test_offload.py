@@ -23,10 +23,11 @@ pins the contract three ways:
    state plane per dtype bucket, the double-buffer bound the dry-run's
    ``offload.staging_bytes_per_device`` reports.
 
-On this CPU container there is no ``pinned_host`` memory space, so the host
-placement is structural (``host_memory_kind()`` is None and the transfer
-annotations are identity); the chunk grid, scan structure, and numerics are
-exactly what a TPU run executes — only the memory-space annotation differs.
+The CPU backend reports a ``pinned_host`` memory, as a TPU does, so the
+chunk hand-offs are annotated here too (``jax.memory.Space.Host`` /
+``.Device``); XLA:CPU keeps the arrays in one memory, so the placement itself
+shows only on a chip or in a TPU compile. The chunk grid, scan structure,
+and numerics are exactly what a TPU run executes.
 """
 import dataclasses
 
@@ -159,6 +160,16 @@ def test_tree_offload_restore_roundtrip(rng):
     back = off.tree_restore(host)
     for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(back)):
         np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_offload_annotates_host_memory(rng):
+    """Offload is not a silent no-op: the backend reports a pinned host
+    memory, and the offloaded chunks leave the program placed in it."""
+    assert off.host_memory_kind() == "pinned_host"
+    px = pack(_params(rng, False))
+    plan = off.OffloadPlan.for_layout(px.layout, _CHUNK_MB)
+    down = jax.jit(lambda p: off.offload_plane(p, plan)).lower(px).as_text()
+    assert down.count('memory_kind = "pinned_host"') == len(px.buffers)
 
 
 # ---------------------------------------------------------------------------

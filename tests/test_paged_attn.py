@@ -201,7 +201,7 @@ def test_ragged_joint_decode_bitwise_per_row(rng):
 
 
 def test_append_kernel_interpret_parity(rng):
-    pool = jnp.zeros((9, 8, 2, 16), jnp.float32)  # page_size 8 → kernel-eligible
+    pool = jnp.asarray(rng.normal(size=(9, 2, 8, 16)), jnp.float32)  # (P, KV, page 8, D)
     new = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
     pt = jnp.asarray(rng.permutation(8)[:6].reshape(3, 2) + 1, jnp.int32)
     lens = jnp.asarray([0, 5, 13], jnp.int32)
@@ -217,7 +217,7 @@ def test_append_kernel_interpret_parity(rng):
 @pytest.mark.parametrize("window", [None, 10])
 def test_attend_kernel_interpret_parity(window, rng):
     s, kv, g, d, page, maxp = 3, 2, 4, 16, 8, 3
-    pool_k = jnp.asarray(rng.normal(size=(s * maxp + 1, page, kv, d)), jnp.float32)
+    pool_k = jnp.asarray(rng.normal(size=(s * maxp + 1, kv, page, d)), jnp.float32)
     pool_v = jnp.asarray(rng.normal(size=pool_k.shape), jnp.float32)
     pt = jnp.arange(1, s * maxp + 1, dtype=jnp.int32).reshape(s, maxp)
     lens = jnp.asarray([2, 11, 23], jnp.int32)
@@ -238,7 +238,7 @@ def test_ops_route_to_kernel_when_forced(rng):
     mode on CPU: results must agree with the reference within kernel tolerance."""
     from repro.kernels.paged_attn import ops
 
-    pool = jnp.asarray(rng.normal(size=(7, 8, 2, 16)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(7, 2, 8, 16)), jnp.float32)  # (P, KV, page, D)
     new = jnp.asarray(rng.normal(size=(2, 1, 2, 16)), jnp.float32)
     pt = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
     lens = jnp.asarray([4, 17], jnp.int32)
@@ -300,6 +300,30 @@ def test_paged_gather_reconstructs_position_order(rng):
     assert g.shape == (2, 8, 3)
     np.testing.assert_array_equal(np.asarray(g[0, :4]), np.asarray(pool[2]))
     np.testing.assert_array_equal(np.asarray(g[1, 4:]), np.asarray(pool[3]))
+
+
+def test_paged_gather_kv_matches_dense_layout(rng):
+    """A kv-head-major GQA pool gathers into the dense (S, L, KV, D) cache."""
+    pool = jnp.asarray(rng.normal(size=(5, 2, 4, 3)), jnp.float32)  # (P, KV, page, D)
+    pt = jnp.asarray([[2, 4], [1, 3]], jnp.int32)
+    g = pa_ref.paged_gather_kv(pool, pt)
+    assert g.shape == (2, 8, 2, 3)
+    np.testing.assert_array_equal(np.asarray(g[0, :4]), np.asarray(pool[2]).transpose(1, 0, 2))
+    np.testing.assert_array_equal(np.asarray(g[1, 4:]), np.asarray(pool[3]).transpose(1, 0, 2))
+
+
+def test_pallas_path_refuses_untileable_page_size(rng):
+    """The kernels' page block must tile on the TPU: page_size 4 is an error
+    on the Pallas path (no silent jnp fallback), and fine on the reference."""
+    from repro.kernels.paged_attn import ops
+
+    pool = jnp.zeros((5, 2, 4, 16), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, 1, 2, 16)), jnp.float32)
+    pt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    lens = jnp.asarray([1, 5], jnp.int32)
+    ops.paged_append(pool, new, pt, lens)
+    with flags.force_pallas(), pytest.raises(ValueError, match="page_size % 8"):
+        ops.paged_append(pool, new, pt, lens)
 
 
 def test_append_targets_clamps_past_table_end():

@@ -263,8 +263,8 @@ def test_swap_under_load_bitwise_equals_restart_on_new_plane(rng):
 
 def test_live_fit_anchor_plane_swap_is_zero_copy(rng):
     """Serving a training run's anchor: fit → serve → fit more → swap the
-    fresh anchor in. The engine serves the trainer's plane buffers by
-    reference at every point — no copy is ever made."""
+    fresh anchor in. anchor_plane() copies z out of the train state (the
+    next round donates it); the swap into the engine makes no copy."""
     from repro.api import Experiment
 
     exp = Experiment(arch="qwen2-7b", strategy="overlap_local_sgd", workers=2, rounds=1)
@@ -275,8 +275,32 @@ def test_live_fit_anchor_plane_swap_is_zero_copy(rng):
     eng.step()
     exp.fit()  # the anchor advances under the live engine
     z = exp.anchor_plane()
-    assert all(a is b for a, b in zip(z.buffers, exp.state.vars.z.buffers))  # no copy out of state
+    for a, b in zip(z.buffers, exp.state.vars.z.buffers):
+        assert a is not b and a.unsafe_buffer_pointer() != b.unsafe_buffer_pointer()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     eng.swap_plane(z)
     res = eng.run()
     assert len(res["a"]) == 4
     assert all(a is b for a, b in zip(eng.plane.buffers, z.buffers))  # no copy into the engine
+
+
+def test_engine_serving_the_anchor_survives_a_later_fit(rng):
+    """fit → swap the anchor into a live engine → fit again → decode. The
+    round donates its input state; the engine's plane must not be among
+    the donated buffers."""
+    from repro.api import Experiment
+
+    exp = Experiment(arch="qwen2-7b", strategy="overlap_local_sgd", workers=2, rounds=1)
+    exp.fit()
+    eng = exp.serve(slots=2, max_len=32, page_size=8)
+    cfg = exp.model_cfg
+    eng.submit("a", rng.integers(1, cfg.vocab_size, (6,)).astype(np.int32), 6)
+    eng.step()
+    eng.swap_plane(exp.anchor_plane())
+    eng.step()  # the swap is applied here
+    before = exp.state
+    exp.fit()
+    assert all(b.is_deleted() for b in before.x.buffers)  # the round did donate
+    assert not any(b.is_deleted() for b in eng.plane.buffers)
+    res = eng.run()
+    assert len(res["a"]) == 6
