@@ -57,6 +57,7 @@ from repro.optim import from_config as opt_from_config
 from repro.optim import schedules
 from repro.optim.optimizers import Optimizer
 from repro.training import consensus_params, make_round_step, make_train_state
+from repro.utils import trace
 
 
 @dataclass
@@ -147,6 +148,8 @@ class Experiment:
             raise ValueError("specify either arch= (LM) or task= (classification), not both")
         self._built = False
         self.state = None
+        # rounds finished, local steps in them, and their target tokens (LM)
+        self.counters = trace.Counters()
 
     # -- construction -------------------------------------------------------
 
@@ -284,16 +287,28 @@ class Experiment:
         t0 = time.time()
         state = self.state
         for r in range(rounds):
-            rb = round_batch(self.next_batch, tau)
-            state, ms = self.step_fn(_unshared(state), rb)
-            self.state = state  # the previous state was donated
-            loss = float(np.asarray(ms["loss"]).mean())
+            with trace.span("fit.round", round=self.counters["rounds"], tau=tau):
+                with trace.span("fit.batch"):
+                    rb = round_batch(self.next_batch, tau)
+                with trace.span("fit.dispatch"):
+                    state, ms = self.step_fn(_unshared(state), rb)
+                self.state = state  # the previous state was donated
+                with trace.span("fit.loss_read"):
+                    loss = float(np.asarray(ms["loss"]).mean())
+                self._count_round(tau, rb)
             losses.append(loss)
             if log is not None:
                 log(r, loss)
         return FitResult(
             losses=losses, state=state, rounds=rounds, steps=rounds * tau, wall_s=time.time() - t0
         )
+
+    def _count_round(self, tau: int, rb) -> None:
+        c = self.counters
+        c["rounds"] += 1
+        c["local_steps"] += tau
+        if isinstance(rb, dict) and "targets" in rb:
+            c["tokens"] += int(rb["targets"].size)
 
     def _ensure_tau_programs(self) -> None:
         if not hasattr(self, "tau_programs"):
@@ -325,10 +340,12 @@ class Experiment:
         state = self.state
         for r in range(rounds):
             tau = ctrl.tau
-            step = self.tau_programs.program_for(tau)
-            rb = round_batch(self.next_batch, tau)
-            state, ms = step(state, rb)
-            losses.append(float(np.asarray(ms["loss"]).mean()))
+            with trace.span("fit.round", round=self.counters["rounds"], tau=tau):
+                step = self.tau_programs.program_for(tau)
+                rb = round_batch(self.next_batch, tau)
+                state, ms = step(state, rb)
+                losses.append(float(np.asarray(ms["loss"]).mean()))
+                self._count_round(tau, rb)
             ctrl.update(float(ms["consensus_drift"]), float(ms["consensus_scale"]))
             total_steps += tau
             if log is not None:
@@ -367,13 +384,15 @@ class Experiment:
         t0 = time.time()
         state = self.state
         for r in range(rounds):
-            state = harness.before_round(state, r)
             tau = ctrl.tau if ctrl is not None else self.strategy_obj.tau
-            step = self.tau_programs.program_for(tau) if ctrl is not None else self.step_fn
-            rb = round_batch(self.next_batch, tau)
-            state, ms = step(_unshared(state), rb)
-            self.state = state
-            losses.append(float(np.asarray(ms["loss"]).mean()))
+            with trace.span("fit.round", round=self.counters["rounds"], tau=tau):
+                state = harness.before_round(state, r)
+                step = self.tau_programs.program_for(tau) if ctrl is not None else self.step_fn
+                rb = round_batch(self.next_batch, tau)
+                state, ms = step(_unshared(state), rb)
+                self.state = state
+                losses.append(float(np.asarray(ms["loss"]).mean()))
+                self._count_round(tau, rb)
             if ctrl is not None:
                 ctrl.update(
                     float(ms["consensus_drift"]),

@@ -79,8 +79,9 @@ def _bwd(causal, window, q_offset, res, g):
     def f(q, k, v):
         return _ref.chunked_mha(q, k, v, causal=causal, window=window, q_offset=q_offset)
 
-    _, vjp = jax.vjp(f, q, k, v)
-    return vjp(g)
+    with jax.named_scope("attention_bwd"):  # the name a device trace finds it by
+        _, vjp = jax.vjp(f, q, k, v)
+        return vjp(g)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -35,6 +35,7 @@ from repro.models import transformer as T
 from repro.parallel.packing import Packed, ParamView
 from repro.serving.paged_cache import PagedState, init_paged_pools, pages_for, paged_supported
 from repro.serving.scheduler import Request, Scheduler
+from repro.utils import trace
 
 
 def prefill(cfg: ModelConfig, params, inputs) -> Tuple[jnp.ndarray, dict]:
@@ -207,7 +208,10 @@ class BatchedEngine:
                 raise ValueError("paged serving requires an attention-only text arch")
         self.results: dict = {}
         self.queue: list = []  # dense-fallback queue
-        self.steps = 0
+        # what the paged ticks did: ticks; prefill chunks and their prompt
+        # tokens; joint decodes, their slot-tokens and the resident tokens
+        # they attended; tokens generated; bytes copied device -> host
+        self.counters = trace.Counters()
         if self.paged:
             self.page_size = page_size
             self.chunk = chunk
@@ -220,7 +224,17 @@ class BatchedEngine:
             # has no donation support, so skip it there (avoids the warning —
             # the structural no-copy claim is pinned by the jaxpr test)
             donate = (3,) if jax.default_backend() == "tpu" else ()
-            self._step_jit = jax.jit(functools.partial(paged_step, cfg), donate_argnums=donate)
+
+            # two names for the one paged step, so that a trace tells its
+            # programs apart: jit_serve_prefill_chunk and jit_serve_decode
+            def serve_prefill_chunk(params, tokens, caches, page_tables, lengths):
+                return paged_step(cfg, params, tokens, caches, page_tables, lengths)
+
+            def serve_decode(params, tokens, caches, page_tables, lengths):
+                return paged_step(cfg, params, tokens, caches, page_tables, lengths)
+
+            self._prefill_jit = jax.jit(serve_prefill_chunk, donate_argnums=donate)
+            self._decode_jit = jax.jit(serve_decode, donate_argnums=donate)
 
     # -- request intake ------------------------------------------------------
 
@@ -292,8 +306,8 @@ class BatchedEngine:
 
     # -- paged engine loop ---------------------------------------------------
 
-    def _run_step(self, tokens, page_tables, lengths):
-        logits, self.pools = self._step_jit(
+    def _run_step(self, program, tokens, page_tables, lengths):
+        logits, self.pools = program(
             self._params_arg(),
             jnp.asarray(tokens),
             self.pools,
@@ -306,65 +320,88 @@ class BatchedEngine:
         """One scheduler tick: apply a pending plane swap, complete finished
         requests (freeing their pages), admit, advance every prefilling slot
         by one chunk, then run one joint decode across active slots. Returns
-        the request ids completed this tick."""
+        the request ids completed this tick. Each phase is a span inside
+        ``repro.engine.tick`` (:mod:`repro.utils.trace`), and ``counters``
+        counts its work."""
         if not self.paged:
             raise RuntimeError("step() drives the paged engine; the dense fallback runs via run()")
-        if self._pending_plane is not None:  # between decode steps, never mid-step
-            self._plane = self._pending_plane
-            self._pending_plane = None
-        sched = self.sched
-        finished = []
-        for i in range(self.slots):
-            a = sched.active[i]
-            if a is not None and a.finished:
-                self.results[a.req.rid] = np.asarray(a.generated, np.int32)
-                finished.append(a.req.rid)
-                sched.complete(i)
-        sched.admit()
-        # chunked prefill: each prefilling slot advances one chunk (B=1 call)
-        for i in range(self.slots):
-            a = sched.active[i]
-            if a is None or a.prefill_done:
-                continue
-            start = a.length
-            end = min(start + self.chunk, len(a.req.prompt))
-            if not sched.ensure_pages(i, end - 1):
-                continue  # evicted itself to make room; requeued
-            toks = np.zeros((1, self.chunk), np.int32)
-            toks[0, : end - start] = a.req.prompt[start:end]
-            logits = self._run_step(toks, sched.table[i : i + 1], np.asarray([start], np.int32))
-            a.length = end
-            if end == len(a.req.prompt):
-                a.prefill_done = True
-                a.generated.append(int(np.argmax(np.asarray(logits)[0, end - start - 1])))
-        # joint decode across every decode-ready slot
-        dec = []
-        for i in range(self.slots):
-            a = sched.active[i]
-            if a is None or not a.prefill_done or a.finished:
-                continue
-            if sched.ensure_pages(i, a.length):  # the append position
-                dec.append((i, a.admit_seq))
-        dec = [
-            i for i, seq in dec
-            if sched.active[i] is not None and sched.active[i].admit_seq == seq
-        ]
-        if dec:
-            toks = np.zeros((self.slots, 1), np.int32)
-            tables = np.zeros_like(sched.table)  # idle rows → trash page, length 0
-            lens = np.zeros((self.slots,), np.int32)
-            for i in dec:
+        sched, c = self.sched, self.counters
+        with trace.span("engine.tick", tick=c["ticks"]) as tick:
+            with trace.span("engine.schedule"):
+                if self._pending_plane is not None:  # between decode steps, never mid-step
+                    self._plane = self._pending_plane
+                    self._pending_plane = None
+                finished = []
+                for i in range(self.slots):
+                    a = sched.active[i]
+                    if a is not None and a.finished:
+                        self.results[a.req.rid] = np.asarray(a.generated, np.int32)
+                        finished.append(a.req.rid)
+                        sched.complete(i)
+                sched.admit()
+            # chunked prefill: each prefilling slot advances one chunk (B=1 call)
+            prefilling = 0
+            for i in range(self.slots):
                 a = sched.active[i]
-                toks[i, 0] = a.generated[-1]
-                tables[i] = sched.table[i]
-                lens[i] = a.length
-            logits = self._run_step(toks, tables, lens)
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-            for i in dec:
-                a = sched.active[i]
-                a.length += 1
-                a.generated.append(int(nxt[i]))
-        self.steps += 1
+                if a is None or a.prefill_done:
+                    continue
+                start = a.length
+                end = min(start + self.chunk, len(a.req.prompt))
+                with trace.span("engine.schedule"):
+                    if not sched.ensure_pages(i, end - 1):
+                        continue  # evicted itself to make room; requeued
+                prefilling += 1
+                with trace.span("engine.prefill", slot=i, tokens=end - start):
+                    toks = np.zeros((1, self.chunk), np.int32)
+                    toks[0, : end - start] = a.req.prompt[start:end]
+                    logits = self._run_step(self._prefill_jit, toks, sched.table[i : i + 1],
+                                            np.asarray([start], np.int32))
+                a.length = end
+                c["prefill_chunks"] += 1
+                c["prefill_tokens"] += end - start
+                if end == len(a.req.prompt):
+                    with trace.span("engine.prefill_readback", slot=i):
+                        a.prefill_done = True
+                        a.generated.append(int(np.argmax(np.asarray(logits)[0, end - start - 1])))
+                    c["readback_bytes"] += logits.nbytes
+                    c["generated_tokens"] += 1
+            # joint decode across every decode-ready slot
+            with trace.span("engine.schedule"):
+                dec = []
+                for i in range(self.slots):
+                    a = sched.active[i]
+                    if a is None or not a.prefill_done or a.finished:
+                        continue
+                    if sched.ensure_pages(i, a.length):  # the append position
+                        dec.append((i, a.admit_seq))
+                dec = [
+                    i for i, seq in dec
+                    if sched.active[i] is not None and sched.active[i].admit_seq == seq
+                ]
+            if dec:
+                with trace.span("engine.decode", slots=len(dec)):
+                    toks = np.zeros((self.slots, 1), np.int32)
+                    tables = np.zeros_like(sched.table)  # idle rows → trash page, length 0
+                    lens = np.zeros((self.slots,), np.int32)
+                    for i in dec:
+                        a = sched.active[i]
+                        toks[i, 0] = a.generated[-1]
+                        tables[i] = sched.table[i]
+                        lens[i] = a.length
+                    logits = self._run_step(self._decode_jit, toks, tables, lens)
+                with trace.span("engine.sample"):
+                    nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                    for i in dec:
+                        a = sched.active[i]
+                        a.length += 1
+                        a.generated.append(int(nxt[i]))
+                        c["decode_kv_tokens"] += a.length  # the appended token's K/V is attended too
+                c["decode_steps"] += 1
+                c["decode_tokens"] += len(dec)
+                c["generated_tokens"] += len(dec)
+                c["readback_bytes"] += nxt.nbytes
+            c["ticks"] += 1
+            tick.set_metadata(prefilling=prefilling, decoding=len(dec))
         return finished
 
     def run(self) -> dict:

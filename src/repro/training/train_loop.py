@@ -77,6 +77,25 @@ from repro.parallel.packing import Packed, ParamView, pack
 from repro.training.train_state import TrainState
 
 
+def _scoped_grad(loss_fn: Callable) -> Callable:
+    """``jax.grad(loss_fn, has_aux=True)`` with the forward pass under the
+    name scope ``forward`` and the backward pass under ``backward``, so that
+    a device trace can tell the two apart. Scopes name operations; they do
+    not change them."""
+
+    def grad(params, batch):
+        def forward(p):
+            with jax.named_scope("forward"):
+                return loss_fn(p, batch)
+
+        loss, vjp, aux = jax.vjp(forward, params, has_aux=True)
+        with jax.named_scope("backward"):
+            (g,) = vjp(jnp.ones_like(loss))
+        return g, aux
+
+    return grad
+
+
 def make_round_step(
     loss_fn: Callable,  # (params, batch) -> (loss, metrics)
     optimizer: Optimizer,
@@ -122,9 +141,9 @@ def make_round_step(
             losses, metrics = jax.vmap(loss_fn)(view, micro)
             return jnp.sum(losses), metrics
 
-        worker_grads = jax.grad(_summed_loss, has_aux=True)
+        worker_grads = _scoped_grad(_summed_loss)
     else:
-        worker_grads = jax.vmap(jax.grad(loss_fn, has_aux=True))
+        worker_grads = jax.vmap(_scoped_grad(loss_fn))
 
     def stacked_grads(x, micro):
         """Per-worker grads, with optional gradient accumulation over
@@ -172,16 +191,18 @@ def make_round_step(
                     grads = jax.vmap(lambda g: clip_by_global_norm(g, grad_clip)[0])(grads)
             if packed_step:
                 pg, vars = strategy.transform_grads_packed(grads, vars)
-                if offload_on:
-                    # streamed update: the host-resident state buckets walk
-                    # through the two device staging chunks per bucket
-                    opt, x = optimizer.step_streamed(opt, x, pg, lr)
-                else:
-                    opt, x = optimizer.step_packed(opt, x, pg, lr)
+                with jax.named_scope("opt_step"):
+                    if offload_on:
+                        # streamed update: the host-resident state buckets walk
+                        # through the two device staging chunks per bucket
+                        opt, x = optimizer.step_streamed(opt, x, pg, lr)
+                    else:
+                        opt, x = optimizer.step_packed(opt, x, pg, lr)
                 x = strategy.local_post_update_packed(x, vars, inflight, k_in_round)
             else:
                 grads, vars = strategy.transform_grads(grads, vars)
-                opt, x = jax.vmap(lambda o, xi, gi: optimizer.step(o, xi, gi, lr))(opt, x, grads)
+                with jax.named_scope("opt_step"):
+                    opt, x = jax.vmap(lambda o, xi, gi: optimizer.step(o, xi, gi, lr))(opt, x, grads)
                 x = strategy.local_post_update(x, vars, inflight, k_in_round)
             metrics = dict(metrics, lr=jnp.broadcast_to(lr, metrics["loss"].shape))
             return (x, opt, vars, step + 1), metrics
@@ -231,13 +252,14 @@ def make_round_step(
         # rounds) masks the boundary; it is carried through unchanged — the
         # harness owns installing/clearing it between rounds (DESIGN.md §7)
         membership = state.membership
-        if probe:
-            x, vars, inflight, stats = strategy.boundary_round(
-                x, vars, inflight, axes_tree, probe=True, membership=membership
-            )
-            metrics = dict(metrics, consensus_drift=stats.drift, consensus_scale=stats.scale)
-        else:
-            x, vars, inflight = strategy.boundary_round(x, vars, inflight, axes_tree, membership=membership)
+        with jax.named_scope("boundary"):
+            if probe:
+                x, vars, inflight, stats = strategy.boundary_round(
+                    x, vars, inflight, axes_tree, probe=True, membership=membership
+                )
+                metrics = dict(metrics, consensus_drift=stats.drift, consensus_scale=stats.scale)
+            else:
+                x, vars, inflight = strategy.boundary_round(x, vars, inflight, axes_tree, membership=membership)
         if offload_on:
             # D2H: the boundary's outputs go back host-resident until the
             # next window needs them (opt state already streamed back
