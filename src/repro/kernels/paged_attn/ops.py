@@ -62,7 +62,8 @@ def paged_append(pool, new, page_tables, lengths):
 
 def paged_attend_gqa(q, pool_k, pool_v, page_tables, lengths, *, window: Optional[int] = None):
     """(S, T, H, D) pre-scaled q against the pool. T == 1 routes to the
-    Pallas online-softmax kernel with page-table-driven index maps."""
+    Pallas online-softmax kernel, which copies each slot's live pages by
+    its page table."""
     if q.shape[1] == 1 and _kernel_ok(pool_k):
         s_, _, h, d = q.shape
         kv = pool_k.shape[1]

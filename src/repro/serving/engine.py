@@ -209,8 +209,8 @@ class BatchedEngine:
         self.results: dict = {}
         self.queue: list = []  # dense-fallback queue
         # what the paged ticks did: ticks; prefill chunks and their prompt
-        # tokens; joint decodes, their slot-tokens and the resident tokens
-        # they attended; tokens generated; bytes copied device -> host
+        # tokens; joint decodes, their slot-tokens, and the resident tokens
+        # and pages they attended; tokens generated; bytes copied device -> host
         self.counters = trace.Counters()
         if self.paged:
             self.page_size = page_size
@@ -306,6 +306,13 @@ class BatchedEngine:
 
     # -- paged engine loop ---------------------------------------------------
 
+    def _pages_attended(self, pos: int) -> int:
+        """Pages a decode reads for a token appended at ``pos``: its
+        window's first page (page 0 without a window) through its own."""
+        window = self.cfg.attention.sliding_window
+        first = 0 if window is None else max(pos - window + 1, 0) // self.page_size
+        return pos // self.page_size - first + 1
+
     def _run_step(self, program, tokens, page_tables, lengths):
         logits, self.pools = program(
             self._params_arg(),
@@ -393,6 +400,7 @@ class BatchedEngine:
                     nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
                     for i in dec:
                         a = sched.active[i]
+                        c["decode_kv_pages"] += self._pages_attended(a.length)
                         a.length += 1
                         a.generated.append(int(nxt[i]))
                         c["decode_kv_tokens"] += a.length  # the appended token's K/V is attended too

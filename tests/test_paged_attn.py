@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.config import get_arch
 from repro.kernels import flags
@@ -214,22 +215,52 @@ def test_append_kernel_interpret_parity(rng):
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
-@pytest.mark.parametrize("window", [None, 10])
-def test_attend_kernel_interpret_parity(window, rng):
-    s, kv, g, d, page, maxp = 3, 2, 4, 16, 8, 3
+def _dead_pages(pt, lens, page, window):
+    """The pages the tables name that no slot's new token can see: past its
+    length, or before its window."""
+    named, live = set(), set()
+    for row, n in zip(np.asarray(pt).tolist(), np.asarray(lens).tolist()):
+        lo = 0 if window is None else max(n - window + 1, 0) // page
+        named |= set(row)
+        live |= set(row[lo : n // page + 1])
+    return sorted(named - live)
+
+
+@pytest.mark.parametrize("window,poisoned", [
+    pytest.param(None, False, id="None"),
+    pytest.param(10, False, id="10"),
+    pytest.param(None, True, id="None-nan"),
+    pytest.param(10, True, id="10-nan"),
+])
+def test_attend_kernel_interpret_parity(window, poisoned, rng):
+    """The kernel against the f32 reference. Page 8 gives blocks of 64 pages
+    (512 tokens); 150 pages are two whole blocks and a part. The lengths are
+    an idle slot (0, the trash row), short ones, the last token of block 0
+    (511), the first of block 1 (512), one past it (513) and one in the last
+    block. Poisoned, every page a table names but its slot's new token
+    cannot see is NaN in the kernel's pool (the reference reads a clean
+    one): the kernel never reads a skipped page. The TPU interpreter runs
+    the page copies and their semaphores, with scratch memory NaN until
+    written."""
+    s, kv, g, d, page, maxp = 7, 2, 4, 16, 8, 150
+    assert pa_kernel.pages_per_block(page, maxp) == 64
     pool_k = jnp.asarray(rng.normal(size=(s * maxp + 1, kv, page, d)), jnp.float32)
     pool_v = jnp.asarray(rng.normal(size=pool_k.shape), jnp.float32)
-    pt = jnp.arange(1, s * maxp + 1, dtype=jnp.int32).reshape(s, maxp)
-    lens = jnp.asarray([2, 11, 23], jnp.int32)
+    pt = jnp.arange(1, s * maxp + 1, dtype=jnp.int32).reshape(s, maxp).at[0].set(0)
+    lens = jnp.asarray([0, 2, 11, 511, 512, 513, 1100], jnp.int32)
     q = jnp.asarray(rng.normal(size=(s, 1, kv * g, d)), jnp.float32)
     want = pa_ref.paged_attend_gqa(q, pool_k, pool_v, pt, lens, window=window)
+    if poisoned:
+        dead = jnp.asarray(_dead_pages(pt, lens, page, window))
+        assert 0 not in dead.tolist() and dead.size > 500  # the idle slot's trash page is live
+        pool_k, pool_v = pool_k.at[dead].set(jnp.nan), pool_v.at[dead].set(jnp.nan)
+    pad = ((0, 0), (0, 0), (0, 0), (0, 112))
     qk = jnp.pad(q.reshape(s, kv, g, d), ((0, 0), (0, 0), (0, 4), (0, 112)))
     got = pa_kernel.paged_attend_decode(
-        qk,
-        jnp.pad(pool_k, ((0, 0), (0, 0), (0, 0), (0, 112))),
-        jnp.pad(pool_v, ((0, 0), (0, 0), (0, 0), (0, 112))),
-        pt, lens, window=window, interpret=True,
+        qk, jnp.pad(pool_k, pad), jnp.pad(pool_v, pad), pt, lens, window=window,
+        interpret=pltpu.InterpretParams(detect_races=True),
     )[:, :, :g, :d].reshape(s, 1, kv * g, d)
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), atol=2e-6, rtol=2e-6)
 
 

@@ -81,3 +81,30 @@ def test_audio_decode_shapes(rng):
     caches = _grow_all(caches, cfg, 9)
     step_logits, _ = decode_step(cfg, params, toks[..., -1:], caches, jnp.asarray(8, jnp.int32))
     assert step_logits.shape == (2, k, 1, cfg.vocab_size)
+
+
+@pytest.mark.parametrize("window", [None, 6])
+def test_batched_engine_counts_decode_kv_pages(window, rng):
+    """``decode_kv_pages`` counts, over every joint decode and every slot in
+    it, the pages that hold a position the new token attends: its window's
+    (or the whole prefix's) through its own append position."""
+    import dataclasses
+
+    cfg = get_arch("qwen2-7b").model.reduced()
+    cfg = dataclasses.replace(cfg, attention=dataclasses.replace(cfg.attention, sliding_window=window))
+    params, _ = T.init_model(cfg, jax.random.PRNGKey(0))
+    page = 4
+    eng = BatchedEngine(cfg, params, slots=2, max_len=32, page_size=page, chunk=8)
+    prompts = {"a": (3, 6), "b": (8, 5), "c": (13, 2)}  # (prompt, max_new): pages cross and end mid-page
+    for rid, (n, m) in prompts.items():
+        eng.submit(rid, rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32), max_new=m)
+    eng.run()
+
+    def pages(pos):
+        lo = 0 if window is None else max(pos - window + 1, 0)
+        return len({k // page for k in range(lo, pos + 1)})
+
+    # the first token comes from the prefill; decode i appends at n + i
+    want = sum(pages(n + i) for n, m in prompts.values() for i in range(m - 1))
+    assert eng.counters["decode_kv_pages"] == want
+    assert eng.counters["decode_tokens"] == sum(m - 1 for _, m in prompts.values())

@@ -5,7 +5,8 @@ that do not tile, matmuls that do not accumulate in f32, VMEM overruns. Each
 test here compiles one kernel with ``interpret=False`` at h2o-danube-1.8b
 widths (d_model 2560, 32 q / 8 kv heads, head_dim 80 padded to 128, d_ff
 6912, sequence 2048, sliding window 4096) against a ``v5e:2x2`` topology and
-checks the kernel survives as a ``tpu_custom_call``.
+checks the kernel survives as a ``tpu_custom_call``; the paged decode
+attend also at qwen2-7b's widths over the chat cell's page tables.
 
 The kernels are called through ``kernel.py`` directly: ``ops.py`` routes by
 the process's default backend, which is the CPU here. The topology is
@@ -110,6 +111,17 @@ def test_paged_attend_decode_compiles(one_chip):
     group_pad = 8  # 32 / 8 = 4 q heads per kv head, padded to a sublane multiple
     q = _sds((SLOTS, KV_HEADS, group_pad, HEAD_DIM_PAD), jnp.bfloat16, one_chip)
     _assert_kernel(pa_k.paged_attend_decode, q, pool, pool, pt, lens, window=WINDOW, interpret=False)
+
+
+@pytest.mark.parametrize("slots", [16, 64])
+def test_paged_attend_decode_compiles_at_chat_shapes(one_chip, slots):
+    """qwen2-7b's decode attend (4 kv heads, 7 q heads each padded to 8,
+    head_dim 128, no window) over tables of 160 pages of 16: the chat cell's
+    16 slots, and 64."""
+    pool = _sds((slots * 160 + 1, 4, PAGE, 128), jnp.bfloat16, one_chip)
+    q = _sds((slots, 4, 8, 128), jnp.bfloat16, one_chip)
+    pt, lens = _sds((slots, 160), jnp.int32, one_chip), _sds((slots,), jnp.int32, one_chip)
+    _assert_kernel(pa_k.paged_attend_decode, q, pool, pool, pt, lens, window=None, interpret=False)
 
 
 def test_paged_append_decode_compiles(one_chip):
